@@ -170,6 +170,29 @@ def _leaf_bytes_device(x: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(x, jnp.uint8).reshape(-1)
 
 
+def _leaf_words_device(x: jax.Array) -> jax.Array:
+    """``_leaf_bytes_device`` viewed as little-endian uint32 words, for
+    a 1-, 2- or 4-byte array whose byte size is a multiple of 4.
+
+    Built without a byte view: bitcasting between widths goes through a
+    ``(n, k)`` array, and the TPU pads a minor dim ``k < 128`` to a full
+    lane tile (32x for bytes), so a multi-GB state would not fit in
+    HBM.  Narrow elements are packed from strided 1-D slices instead.
+    """
+    x = x.reshape(-1)
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.uint8)
+    k = x.dtype.itemsize
+    if k == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    u = jax.lax.bitcast_convert_type(x, jnp.uint16 if k == 2 else jnp.uint8)
+    per = 4 // k
+    w = u[0::per].astype(jnp.uint32)
+    for j in range(1, per):
+        w = w | (u[j::per].astype(jnp.uint32) << (8 * k * j))
+    return w
+
+
 @dataclass
 class _StreamSpec:
     """Per-(treedef, shapes, precodec) compiled device serializer."""
@@ -185,7 +208,7 @@ class _StageResult:
     mask: np.ndarray           # (n_chunks,) bool dirty mask
     digests: np.ndarray        # (n_chunks,) uint64 raw-chunk digests
     dirty_idx: np.ndarray      # global indices of dirty chunks
-    sub: jax.Array             # (n_dirty, chunk_words) u32, D2H in flight
+    sub: jax.Array             # (n_dirty, chunk_words // 128, 128) u32, D2H in flight
     stage_s: float
 
 
@@ -309,19 +332,25 @@ class DevicePrecodec:
                     qparts.append(flat)
             if qparts:
                 q, s = quantize(jnp.concatenate(qparts), interpret=self.interpret)
-            parts = []
+            pieces = []
             for leaf, qr in zip(leaf_list, quant_rows):
                 if qr is None:
-                    parts.append(_leaf_bytes_device(jnp.asarray(leaf)))
+                    pieces.append(jnp.asarray(leaf))
                 else:
                     a, b = qr
-                    parts.append(_leaf_bytes_device(q[a:b]))
-                    parts.append(_leaf_bytes_device(s[a:b]))
-            u8 = jnp.concatenate(parts) if parts else jnp.zeros((0,), jnp.uint8)
-            pad = (-u8.shape[0]) % 4
-            if pad:
-                u8 = jnp.pad(u8, (0, pad))
-            return jax.lax.bitcast_convert_type(u8.reshape(-1, 4), jnp.uint32)
+                    pieces += [q[a:b], s[a:b]]
+            # zero-pad to whole chunks here, inside the one assembly
+            # pass, so the fused kernel's own pad never copies the stream
+            pad = -((total + 3) // 4) % chunk_words
+            tail = [jnp.zeros((pad,), jnp.uint32)] if pad else []
+            if word_aligned:
+                words = [_leaf_words_device(p) for p in pieces] + tail
+                return jnp.concatenate(words)
+            parts = [_leaf_bytes_device(p) for p in pieces]
+            u8 = jnp.concatenate(parts)
+            u8 = jnp.pad(u8, (0, (-u8.shape[0]) % 4))
+            w = jax.lax.bitcast_convert_type(u8.reshape(-1, 4), jnp.uint32)
+            return jnp.concatenate([w] + tail)
 
         # the transformed leaf table mirrors what the host reference path
         # (quantize_tree -> serialize_tree) would record in the manifest
@@ -340,6 +369,11 @@ class DevicePrecodec:
                 )
             )
             off += size
+        chunk_words, total = self.chunk_size // 4, off
+        # every leaf starts on a word boundary: assemble words directly
+        word_aligned = all(
+            l.size % 4 == 0 and np.dtype(l.dtype).itemsize <= 4 for l in leaves
+        )
         return _StreamSpec(fn=jax.jit(build), leaves=leaves, total=off)
 
     # -- staging ------------------------------------------------------------
@@ -413,7 +447,7 @@ class DevicePrecodec:
         """
         t0 = perf_counter()
         res = staged.future.result()
-        dirty_np = np.asarray(res.sub)
+        dirty_np = np.asarray(res.sub).reshape(len(res.dirty_idx), -1)
         wait_s = perf_counter() - t0
         total, cs = staged.spec.total, self.chunk_size
         deltas: Dict[int, np.ndarray] = {}

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import sums_tile_u32
+
 TILE_ROWS = 8
 TILE_COLS = 128
 TILE = TILE_ROWS * TILE_COLS  # 1024 words per grid step
@@ -27,14 +29,12 @@ def _checksum_kernel(w_ref, out_ref):
     rows = jax.lax.broadcasted_iota(jnp.uint32, (TILE_ROWS, TILE_COLS), 0)
     cols = jax.lax.broadcasted_iota(jnp.uint32, (TILE_ROWS, TILE_COLS), 1)
     idx = rows * jnp.uint32(TILE_COLS) + cols
-    s = jnp.sum(w, dtype=jnp.uint32)
-    t = jnp.sum(idx * w, dtype=jnp.uint32)
-    out_ref[0, 0] = s
-    out_ref[0, 1] = t
+    out_ref[0] = sums_tile_u32(w, idx * w)
 
 
 def checksum_tiles(words: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
-    """words: (n_tiles, 8, 128) uint32 -> (n_tiles, 2) uint32 partials."""
+    """words: (n_tiles, 8, 128) uint32 -> (n_tiles, 8, 128) uint32 with
+    the per-tile partials ``(S, T)`` at ``[:, 0, :2]``."""
     n_tiles = words.shape[0]
     return pl.pallas_call(
         _checksum_kernel,
@@ -42,7 +42,7 @@ def checksum_tiles(words: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
         in_specs=[
             pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda g: (g, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, 2), jnp.uint32),
+        out_specs=pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda g: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, TILE_ROWS, TILE_COLS), jnp.uint32),
         interpret=interpret,
     )(words)

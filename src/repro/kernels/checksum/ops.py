@@ -29,9 +29,9 @@ def checksum_u32(words: jnp.ndarray, *, interpret: bool | None = None) -> jnp.nd
         w = jnp.pad(w, (0, pad))
     n_tiles = w.shape[0] // TILE
     tiles = w.reshape(n_tiles, TILE_ROWS, TILE_COLS)
-    partials = checksum_tiles(tiles, interpret=interpret)  # (n_tiles, 2)
-    s_g = partials[:, 0]
-    t_g = partials[:, 1]
+    partials = checksum_tiles(tiles, interpret=interpret)
+    s_g = partials[:, 0, 0]
+    t_g = partials[:, 0, 1]
     base = (jnp.arange(n_tiles, dtype=jnp.uint32) * jnp.uint32(TILE)) % jnp.uint32(
         IDX_MOD
     )

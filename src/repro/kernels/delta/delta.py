@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import lane_tile
+
 ROWS = 8
 COLS = 128
 TILE = ROWS * COLS
@@ -22,11 +24,12 @@ def _delta_kernel(c_ref, p_ref, d_ref, n_ref):
     p = p_ref[0]
     d = jnp.bitwise_xor(c, p)
     d_ref[0] = d
-    n_ref[0, 0] = jnp.sum((d != 0).astype(jnp.int32))
+    n_ref[0] = lane_tile(jnp.sum((d != 0).astype(jnp.int32)))
 
 
 def delta_tiles(cur: jnp.ndarray, prev: jnp.ndarray, *, interpret: bool):
-    """(n_tiles, 8, 128) u32 x2 -> (delta same shape, counts (n_tiles, 1) i32)."""
+    """(n_tiles, 8, 128) u32 x2 -> (delta same shape, counts (n_tiles, 8, 128)
+    i32 with the tile's changed-word count at ``[:, 0, 0]``)."""
     n = cur.shape[0]
     return pl.pallas_call(
         _delta_kernel,
@@ -37,11 +40,11 @@ def delta_tiles(cur: jnp.ndarray, prev: jnp.ndarray, *, interpret: bool):
         ],
         out_specs=[
             pl.BlockSpec((1, ROWS, COLS), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 1), lambda g: (g, 0)),
+            pl.BlockSpec((1, ROWS, COLS), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, ROWS, COLS), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, ROWS, COLS), jnp.int32),
         ],
         interpret=interpret,
     )(cur, prev)

@@ -30,4 +30,4 @@ def xor_delta(
     ct = c.reshape(-1, ROWS, COLS)
     pt = p.reshape(-1, ROWS, COLS)
     d, counts = delta_tiles(ct, pt, interpret=interpret)
-    return d.reshape(-1)[:n], jnp.sum(counts)
+    return d.reshape(-1)[:n], jnp.sum(counts[:, 0, 0])

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.checksum.ref import IDX_MOD
+from repro.kernels.common import sums_tile_u32
 
 TILE_ROWS = 8
 TILE_COLS = 128
@@ -47,14 +48,13 @@ def _fused_kernel(c_ref, b_ref, d_ref, m_ref):
         + rows * jnp.uint32(TILE_COLS)
         + cols
     ) & jnp.uint32(IDX_MOD - 1)
-    m_ref[0, 0] = jnp.sum((d != 0).astype(jnp.uint32), dtype=jnp.uint32)
-    m_ref[0, 1] = jnp.sum(c, dtype=jnp.uint32)
-    m_ref[0, 2] = jnp.sum(idx * c, dtype=jnp.uint32)
+    m_ref[0] = sums_tile_u32((d != 0).astype(jnp.uint32), c, idx * c)
 
 
 def fused_chunk_tiles(cur: jnp.ndarray, base: jnp.ndarray, *, interpret: bool):
     """(n_chunks, tiles_per_chunk, 8, 128) u32 x2 ->
-    (delta same shape, meta (n_chunks, 3) u32 = (changed, S, T))."""
+    (delta same shape, meta (n_chunks, 8, 128) u32 with
+    ``meta[:, 0, :3] = (changed, S, T)`` and zeros elsewhere)."""
     n, t = cur.shape[0], cur.shape[1]
     return pl.pallas_call(
         _fused_kernel,
@@ -65,11 +65,11 @@ def fused_chunk_tiles(cur: jnp.ndarray, base: jnp.ndarray, *, interpret: bool):
         ],
         out_specs=[
             pl.BlockSpec((1, t, TILE_ROWS, TILE_COLS), lambda g: (g, 0, 0, 0)),
-            pl.BlockSpec((1, 3), lambda g: (g, 0)),
+            pl.BlockSpec((1, TILE_ROWS, TILE_COLS), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, t, TILE_ROWS, TILE_COLS), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 3), jnp.uint32),
+            jax.ShapeDtypeStruct((n, TILE_ROWS, TILE_COLS), jnp.uint32),
         ],
         interpret=interpret,
     )(cur, base)

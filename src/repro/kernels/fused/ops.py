@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.common import interpret_default
-from repro.kernels.fused.fused import TILE, fused_chunk_tiles
+from repro.kernels.fused.fused import TILE, TILE_COLS, fused_chunk_tiles
 
 CHUNK_ALIGN = TILE * 4  # bytes per native tile; chunk_size must be a multiple
 
@@ -28,10 +28,15 @@ def fused_precodec(cur, base, *, chunk_words: int, interpret=None):
 
     ``cur``/``base``: equal-length 1-D uint32 arrays (zero-pad is
     applied here up to a chunk multiple; zero padding is neutral for
-    both the dirty count and the checksum tracks).  Returns
-    ``(delta, meta)`` with ``delta`` shaped ``(n_chunks, chunk_words)``
-    uint32 and ``meta`` shaped ``(n_chunks, 3)`` uint32 rows of
-    ``(changed_words, S, T)``.
+    both the dirty count and the checksum tracks, and a stream that is
+    already a chunk multiple is not copied).  Returns ``(delta, meta)``
+    with ``delta`` shaped ``(n_chunks, chunk_words // 128, 128)`` uint32
+    (chunk ``i``'s words are ``delta[i].reshape(-1)``) and ``meta``
+    shaped ``(n_chunks, 3)`` uint32 rows of ``(changed_words, S, T)``.
+
+    The delta keeps rows of 128 lanes because that is free on a TPU: a
+    ``(n_chunks, chunk_words)`` array tiles 8 chunks per ``(8, 128)``
+    tile, and the relayout would copy the whole delta in HBM.
     """
     if interpret is None:
         interpret = interpret_default()
@@ -52,7 +57,7 @@ def fused_precodec(cur, base, *, chunk_words: int, interpret=None):
     ct = c.reshape(n_chunks, tiles_per_chunk, 8, 128)
     bt = b.reshape(n_chunks, tiles_per_chunk, 8, 128)
     delta, meta = fused_chunk_tiles(ct, bt, interpret=interpret)
-    return delta.reshape(n_chunks, chunk_words), meta
+    return delta.reshape(n_chunks, -1, TILE_COLS), meta[:, 0, :3]
 
 
 def digests_from_meta(meta: np.ndarray) -> np.ndarray:

@@ -7,6 +7,7 @@ device query).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,10 +19,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this process actually has (CPU smoke runs): 1x1 mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    # ``jax.make_mesh`` defaults to Explicit axes, under which a gather
+    # from a vocab-sharded embedding needs an ``out_sharding`` at every
+    # call site; the train step states its shardings through jit instead.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

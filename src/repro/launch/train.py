@@ -16,12 +16,12 @@ import time
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import CheckpointConfig, CheckpointManager, theta_like
 from repro.data import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.train import OptConfig, TrainConfig, init_train_state, make_train_step
@@ -54,8 +54,30 @@ def build_argparser() -> argparse.ArgumentParser:
     return ap
 
 
+def _shardings(mesh, specs):
+    return jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, PartitionSpec),
+    )
+
+
+def place_state(state, mesh, specs):
+    """Put a train state (device or host arrays) onto ``mesh`` under the
+    train step's partition ``specs``.  Host arrays go straight to their
+    shards, never through one whole copy on the first device."""
+    return jax.device_put(state, _shardings(mesh, specs))
+
+
+def init_state(init, mesh, specs):
+    """Run the train-state initialiser ``init`` straight into its shards:
+    each device computes its own part, so no device ever holds the whole
+    state (run eagerly, ``init`` would build all of it on the first)."""
+    return jax.jit(init, out_shardings=_shardings(mesh, specs))()
+
+
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = get_model(cfg)
     mesh = make_host_mesh()
@@ -80,15 +102,6 @@ def main(argv=None) -> int:
     )
     step_fn, state_specs, _ = make_train_step(model, tcfg, mesh, batch_struct)
 
-    def place_state(st):
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        return jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-            st, state_specs,
-            is_leaf=lambda x: not isinstance(x, (dict, list, tuple)),
-        )
-
     cluster = theta_like(args.nodes, args.ppn)
     mgr = CheckpointManager(
         CheckpointConfig(
@@ -103,19 +116,22 @@ def main(argv=None) -> int:
         )
     )
 
-    state = place_state(init_train_state(model, jax.random.PRNGKey(0), tcfg))
-    full_state = {"train": state, "data": data.state_tree()}
+    init = lambda: init_train_state(model, jax.random.PRNGKey(0), tcfg)
+    state = None
     start = 0
     if args.resume:
         try:
-            target = jax.tree_util.tree_map(np.asarray, full_state)
+            # shapes only: a restore target needs no initialised state
+            target = {"train": jax.eval_shape(init), "data": data.state_tree()}
             step, restored = mgr.restore(target)
-            state = place_state(jax.tree_util.tree_map(jnp.asarray, restored["train"]))
+            state = place_state(restored["train"], mesh, state_specs)
             data.load_state(restored["data"])
             start = int(state["step"])
             print(f"[resume] restored step {step} (train step {start})")
         except FileNotFoundError:
             print("[resume] no checkpoint found; cold start")
+    if state is None:
+        state = init_state(init, mesh, state_specs)
 
     t_step_accum = 0.0
     for i in range(start, args.steps):

@@ -132,12 +132,10 @@ def param_spec_for(name: str, shape: Tuple[int, ...], cfg: ModelConfig, mesh: Me
     if any(frag in name for frag in _REPLICATE_NAMES):
         return P()
     if "embed" in name:
-        # Lookup table: vocab over TP, d replicated.  Sharding d would
-        # make XLA reshard the gather *output*, which miscompiles on the
-        # jax 0.8 CPU SPMD partitioner ("slice dim size > dynamic slice
-        # dimension"); vocab-sharded gathers lower to the standard
-        # mask+all-reduce pattern instead.  The untied `out` projection
-        # is a plain matmul and stays sharded on both dims.
+        # Lookup table: vocab over TP, d replicated; a vocab-sharded
+        # gather lowers to the standard mask+all-reduce pattern.  The
+        # untied `out` projection is a plain matmul and stays sharded on
+        # both dims.
         v, d = shape
         return P(_maybe(v, T, mesh), None)
     if "'out'" in name or name.endswith("out']") and "w_out" not in name:

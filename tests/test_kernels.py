@@ -159,7 +159,8 @@ def _fused_vs_ref(cur, base, chunk_words):
         jnp.asarray(cur), jnp.asarray(base), chunk_words=chunk_words
     )
     rd, rc, rg = fused_ref(cur, base, chunk_words)
-    np.testing.assert_array_equal(np.asarray(delta), rd)
+    assert delta.shape == (rd.shape[0], chunk_words // 128, 128)
+    np.testing.assert_array_equal(np.asarray(delta).reshape(rd.shape), rd)
     np.testing.assert_array_equal(np.asarray(meta)[:, 0], rc)
     np.testing.assert_array_equal(np.asarray(digests_from_meta(meta)), rg)
     np.testing.assert_array_equal(np.asarray(dirty_from_meta(meta)), rc > 0)

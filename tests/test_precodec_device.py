@@ -41,6 +41,23 @@ def mixed_state(step=0):
     }
 
 
+def aligned_state(step=0):
+    # every leaf a whole number of 4-byte words: staged through the word
+    # assembly (4-byte bitcast, 2- and 1-byte packing), where mixed_state's
+    # 65-byte flag sends the whole stream through the byte assembly
+    return {
+        "w": jnp.asarray(
+            (RNG.standard_normal((64, 300)) * 3).astype(np.float32) + step
+        ),
+        "tiny": jnp.full((36,), 1.5 + step, jnp.float32),
+        "h": jnp.asarray(RNG.standard_normal((32, 256)).astype(np.float32) + step,
+                         jnp.bfloat16),
+        "i8": jnp.asarray(RNG.integers(-128, 128, 1000), jnp.int8),
+        "flag": jnp.asarray(RNG.random(64) < 0.5),
+        "i": jnp.asarray(RNG.integers(0, 100, 511), jnp.int32),
+    }
+
+
 def bump(state, key="w", amt=0.25):
     state = dict(state)
     state[key] = state[key] + jnp.asarray(amt, state[key].dtype)
@@ -64,10 +81,8 @@ def assert_tree_equal(a, b):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("precodec", ["none", "int8"])
-def test_stage_full_matches_host_serialize(precodec):
+def check_stage_full(state, precodec):
     dev = DevicePrecodec(chunk_size=4096, precodec=precodec)
-    state = mixed_state()
     bufs = dev.consume(dev.stage(1, state))
     stream, leaves = host_stream(state, precodec)
     assert bytes(bufs.stream) == bytes(stream)
@@ -77,10 +92,8 @@ def test_stage_full_matches_host_serialize(precodec):
     dev.close()
 
 
-@pytest.mark.parametrize("precodec", ["none", "int8"])
-def test_stage_delta_matches_host_serialize(precodec):
+def check_stage_delta(s1, precodec):
     dev = DevicePrecodec(chunk_size=4096, precodec=precodec)
-    s1 = mixed_state()
     b1 = dev.consume(dev.stage(1, s1))
     s2 = bump(s1)
     bufs = dev.consume(dev.stage(2, s2, base_step=1), base_stream=b1.stream)
@@ -91,6 +104,26 @@ def test_stage_delta_matches_host_serialize(precodec):
     assert 0 < mask.sum() < mask.size  # touched one leaf -> partial dirty set
     assert set(bufs.deltas) == set(np.flatnonzero(mask))
     dev.close()
+
+
+@pytest.mark.parametrize("precodec", ["none", "int8"])
+def test_stage_full_matches_host_serialize(precodec):
+    check_stage_full(mixed_state(), precodec)
+
+
+@pytest.mark.parametrize("precodec", ["none", "int8"])
+def test_stage_full_aligned_matches_host_serialize(precodec):
+    check_stage_full(aligned_state(), precodec)
+
+
+@pytest.mark.parametrize("precodec", ["none", "int8"])
+def test_stage_delta_matches_host_serialize(precodec):
+    check_stage_delta(mixed_state(), precodec)
+
+
+@pytest.mark.parametrize("precodec", ["none", "int8"])
+def test_stage_delta_aligned_matches_host_serialize(precodec):
+    check_stage_delta(aligned_state(), precodec)
 
 
 def test_stage_base_miss_degrades_to_full():
