@@ -144,6 +144,7 @@ from repro.core.storage import (
     TokenBucket,
     placement_from_plan,
 )
+from repro.core.spans import Span, begin, current, span
 from repro.core.strategies import make_plan
 from repro.core.integrity import crc32
 
@@ -345,6 +346,8 @@ class _FlushJob:
     superseded: bool = False  # set (under the manager lock) by newer saves
     started: bool = False    # scheduler picked it up: no longer preemptible
     preempted: bool = False  # yielded its admission slot (parked, resumable)
+    span: Optional[Span] = None    # the save's root span: parent of the flush
+    queued: Optional[Span] = None  # put -> the worker's get
 
 
 # Scheduler-queue sentinel: run resume_flushes() on the flush worker
@@ -366,6 +369,7 @@ class CheckpointManager:
         tenant: Optional[str] = None,
         priority: float = 1.0,
     ):
+        opening = begin("ckpt.manager.open")
         self.cfg = config
         self.cluster = config.cluster
         self.root = Path(config.root)
@@ -428,9 +432,11 @@ class CheckpointManager:
         # the step currently staged ahead of its save().
         self._device_precodec = None
         self._staged = None
-        self.stats: List[SaveStats] = []
+        # Newest saves' telemetry, bounded like the records below.
+        self.stats: Deque[SaveStats] = deque(maxlen=4096)
         # Flush results are delivered by step through this index (under
-        # _lock) — the flush worker never scans the list save() appends to.
+        # _lock, trimmed with ``stats``) — the flush worker never scans
+        # the deque save() appends to.
         self._stats_by_step: Dict[int, SaveStats] = {}
         # Parsed-manifest cache keyed by (ino, mtime_ns, size) per path:
         # steps() runs per save (via _gc) and per restore candidate scan,
@@ -513,31 +519,21 @@ class CheckpointManager:
                 self._q.put(_AUTO_RESUME)  # re-queue partials on the worker
             else:
                 self.resume_flushes()
+        opening.end()
 
     # ------------------------------------------------------------------ save
 
     def save(self, step: int, state: Any) -> SaveStats:
-        cfg = self.cfg
-        t0 = time.perf_counter()
-        if cfg.precodec not in ("none", "int8"):
-            raise ValueError(f"unknown precodec {cfg.precodec!r}")
-        base = None
-        if cfg.device_precodec:
-            self._check_device_cfg()
-        else:
-            if cfg.precodec == "int8":
-                from repro.core.precodec import quantize_tree
+        with span("ckpt.save", step=int(step)) as root:
+            st = self._save(step, state, root)
+            root.set(raw_bytes=st.raw_bytes, stored_bytes=st.stored_bytes)
+        return st
 
-                state = quantize_tree(state)
-            base = self._delta_base()
+    def _save(self, step: int, state: Any, root: Span) -> SaveStats:
+        cfg = self.cfg
         c = self.cluster
         pool = self._local_pool() if cfg.parallel_local else None
         replicate = cfg.partner_replication and c.n_nodes > 1
-        # L1 backpressure: make room for this step *before* its first
-        # blob lands (the fused path writes L1 inside encode).  The
-        # newest step's cost is the estimate; the post-write true-up
-        # below reconciles against the real size.
-        self._enforce_l1_budget(step, self._last_l1_cost, strict=True)
 
         def drain_rank(rank: int, blob: Any) -> None:
             # non-atomic, unsynced writes: the local manifest written
@@ -555,58 +551,38 @@ class CheckpointManager:
                 )
 
         fused = cfg.zero_copy and pool is not None
-        stage_s = stage_wait_s = 0.0
-        if cfg.device_precodec:
-            enc, stage_s, stage_wait_s = self._encode_device(
+        with span("ckpt.save.encode") as sp_enc:
+            enc, stage_s, stage_wait_s = self._encode(
                 step, state, pool, drain_rank if fused else None
             )
-        elif cfg.zero_copy:
-            # fused parallel local phase: each pooled rank task encodes,
-            # CRCs and writes its L1 blob (+ partner replica) in one go —
-            # CRC of one rank overlaps the file write of another
-            enc = encode_state(
-                step, state, self.cluster, codec=cfg.codec, base=base,
-                chunk_aligned=cfg.chunk_aligned_split,
-                pool=pool, rank_sink=drain_rank if fused else None,
-                chunk_size=cfg.chunk_size,
-            )
-        else:
-            from repro.core.serialize_ref import encode_state_reference
-
-            enc = encode_state_reference(
-                step, state, self.cluster, codec=cfg.codec, base=base
-            )
-        enc.manifest.precodec = cfg.precodec
-        t_enc = time.perf_counter() - t0
 
         # ---- local phase (blocking) ----
-        t1 = time.perf_counter()
-        if pool is None:
-            # seed reference path: sequential writes, fsync per file
-            for rank, blob in enumerate(enc.blobs):
-                node = c.node_of_rank(rank)
-                self.local.write_blob(node, step, rank, blob)
-                if cfg.partner_replication and c.n_nodes > 1:
-                    partner = (node + 1) % c.n_nodes
-                    self.local.write_blob(partner, step, rank, blob, partner=True)
-        else:
-            if not fused:  # reference encode + parallel drain
-                list(pool.map(lambda j: drain_rank(*j), enumerate(enc.blobs)))
-            # batched durability: one fsync per node directory (the
-            # blobs span every rank, hence every node — replicas too)
-            list(pool.map(
-                lambda n: self.local.sync_dir(n, step), range(c.n_nodes)
-            ))
-        enc.manifest.status = "local_done"
-        self._write_manifest_local(enc.manifest)
-        t_local = time.perf_counter() - t1
+        with span("ckpt.save.l1_commit") as sp_local:
+            if pool is None:
+                # seed reference path: sequential writes, fsync per file
+                for rank, blob in enumerate(enc.blobs):
+                    node = c.node_of_rank(rank)
+                    self.local.write_blob(node, step, rank, blob)
+                    if replicate:
+                        partner = (node + 1) % c.n_nodes
+                        self.local.write_blob(partner, step, rank, blob, partner=True)
+            else:
+                if not fused:  # reference encode + parallel drain
+                    list(pool.map(lambda j: drain_rank(*j), enumerate(enc.blobs)))
+                # batched durability: one fsync per node directory (the
+                # blobs span every rank, hence every node — replicas too)
+                list(pool.map(
+                    lambda n: self.local.sync_dir(n, step), range(c.n_nodes)
+                ))
+            enc.manifest.status = "local_done"
+            self._write_manifest_local(enc.manifest)
 
         st = SaveStats(
             step=step,
-            local_time=t_local,
+            local_time=sp_local.seconds,
             raw_bytes=enc.manifest.total_raw_bytes,
             stored_bytes=sum(r.stored_size for r in enc.manifest.ranks),
-            encode_time=t_enc,
+            encode_time=sp_enc.seconds,
             stage_s=stage_s,
             stage_wait_s=stage_wait_s,
         )
@@ -622,6 +598,10 @@ class CheckpointManager:
                 self._saves_since_full += 1
             self._l1_bytes[step] = l1_cost
             self._last_l1_cost = l1_cost
+            if len(self.stats) == self.stats.maxlen:
+                old = self.stats[0]
+                if self._stats_by_step.get(old.step) is old:
+                    del self._stats_by_step[old.step]
             self.stats.append(st)
             self._stats_by_step[step] = st
             self._saved_steps.append(step)
@@ -632,30 +612,33 @@ class CheckpointManager:
                 del self._saved_steps[: len(self._saved_steps) - bound // 2]
 
         # ---- flush phase (async) ----
-        sizes = [r.stored_size for r in enc.manifest.ranks]
-        plan = make_plan(cfg.strategy, c, sizes, **cfg.strategy_kwargs)
-        job = _FlushJob(
-            enc, plan, CancelToken(), protected=self._is_protected(enc.manifest)
-        )
-        if cfg.async_flush:
-            if cfg.supersede_stale:
-                # mark stale pending flushes *before* taking a slot:
-                # skipped jobs release their slots, so a fast save
-                # cadence drains the queue instead of stalling on it
-                self._supersede_stale(step)
-            # backpressure: bounded flush pipeline.  Under a shared
-            # controller this blocks on the CLUSTER budget; offering
-            # _yield_queued_flush makes this manager's queued (never
-            # mid-flight) jobs preemptible by higher-priority tenants.
-            self._admission.acquire(
-                self, priority=self.priority,
-                yield_fn=self._yield_queued_flush,
+        with span("ckpt.save.enqueue"):
+            sizes = [r.stored_size for r in enc.manifest.ranks]
+            plan = make_plan(cfg.strategy, c, sizes, **cfg.strategy_kwargs)
+            job = _FlushJob(
+                enc, plan, CancelToken(),
+                protected=self._is_protected(enc.manifest), span=root,
             )
-            with self._lock:
-                self._pending[step] = job
-            self._q.put(job)
-            self._add_demand(plan.total_bytes)
-        else:
+            if cfg.async_flush:
+                if cfg.supersede_stale:
+                    # mark stale pending flushes *before* taking a slot:
+                    # skipped jobs release their slots, so a fast save
+                    # cadence drains the queue instead of stalling on it
+                    self._supersede_stale(step)
+                # backpressure: bounded flush pipeline.  Under a shared
+                # controller this blocks on the CLUSTER budget; offering
+                # _yield_queued_flush makes this manager's queued (never
+                # mid-flight) jobs preemptible by higher-priority tenants.
+                self._admission.acquire(
+                    self, priority=self.priority,
+                    yield_fn=self._yield_queued_flush,
+                )
+                with self._lock:
+                    self._pending[step] = job
+                job.queued = begin("ckpt.flush.queued", parent=root)
+                self._q.put(job)
+                self._add_demand(plan.total_bytes)
+        if not cfg.async_flush:
             try:
                 st.flush = self._do_flush(job)
             except (CircuitOpenError, OSError) as e:
@@ -669,6 +652,52 @@ class CheckpointManager:
         # raise — the bytes are already durable on L1) if it overshot
         self._enforce_l1_budget(step, 0, strict=False)
         return st
+
+    def _encode(self, step: int, state: Any, pool, rank_sink):
+        """``save()``'s encode phase, from its entry: checks, the host
+        pre-codec, L1 room for the step, then serialize + encode + CRC
+        (+ the L1 writes through ``rank_sink`` on the fused path).
+        Returns ``(enc, stage_s, stage_wait_s)``."""
+        cfg = self.cfg
+        if cfg.precodec not in ("none", "int8"):
+            raise ValueError(f"unknown precodec {cfg.precodec!r}")
+        base = None
+        if cfg.device_precodec:
+            self._check_device_cfg()
+        else:
+            if cfg.precodec == "int8":
+                from repro.core.precodec import quantize_tree
+
+                state = quantize_tree(state)
+            base = self._delta_base()
+        # L1 backpressure: make room for this step *before* its first
+        # blob lands (the fused path writes L1 inside encode).  The
+        # newest step's cost is the estimate; the post-write true-up
+        # in save() reconciles against the real size.
+        self._enforce_l1_budget(step, self._last_l1_cost, strict=True)
+        stage_s = stage_wait_s = 0.0
+        if cfg.device_precodec:
+            enc, stage_s, stage_wait_s = self._encode_device(
+                step, state, pool, rank_sink
+            )
+        elif cfg.zero_copy:
+            # fused parallel local phase: each pooled rank task encodes,
+            # CRCs and writes its L1 blob (+ partner replica) in one go —
+            # CRC of one rank overlaps the file write of another
+            enc = encode_state(
+                step, state, self.cluster, codec=cfg.codec, base=base,
+                chunk_aligned=cfg.chunk_aligned_split,
+                pool=pool, rank_sink=rank_sink,
+                chunk_size=cfg.chunk_size,
+            )
+        else:
+            from repro.core.serialize_ref import encode_state_reference
+
+            enc = encode_state_reference(
+                step, state, self.cluster, codec=cfg.codec, base=base
+            )
+        enc.manifest.precodec = cfg.precodec
+        return enc, stage_s, stage_wait_s
 
     # ------------------------------------------------- device pre-codec path
 
@@ -997,6 +1026,8 @@ class CheckpointManager:
                 finally:
                     self._q.task_done()
                 continue
+            if job.queued is not None:
+                job.queued.end()
             step = job.enc.step
             try:
                 with self._lock:
@@ -1369,42 +1400,50 @@ class CheckpointManager:
     def _do_flush(self, job: _FlushJob) -> FlushResult:
         enc, plan = job.enc, job.plan
         man = enc.manifest
-        man.strategy = plan.strategy
-        man.files = dict(plan.files)
-        man.placement = placement_from_plan(plan)
-        journal: Optional[FlushJournal] = None
-        if self.cfg.resumable_flushes:
-            # commit the write set *before* the first byte: a
-            # flush_partial manifest (full columnar placement + file
-            # sizes) plus the extent journal is everything
-            # resume_flushes() needs after any interruption.  fresh=True:
-            # a journal left by a previous incarnation of this step
-            # describes *different bytes* and must never skip writes here.
-            man.status = "flush_partial"
-            self._write_manifest_pfs(man)
-            journal = FlushJournal(self._journal_path(enc.step), fresh=True)
-        try:
-            res = self.executor.execute(
-                plan, enc.step,
-                cancel=job.token, limiter=self._limiter, journal=journal,
-            )
-        except FlushCancelled:
-            if job.superseded and self.cfg.resumable_flushes:
-                # a superseded partial is dead, not resumable: newer
-                # state already replaced it — mark it so resume skips it
-                man.status = "superseded"
-                self._write_manifest_pfs(man)
-            raise
-        man.status = "flush_done"
-        self._write_manifest_pfs(man)
-        self._notify_flush_done(enc.step)
-        if journal is not None:
-            journal.unlink()
-        if self.cfg.keep_n is not None:
+        with span("ckpt.flush", parent=job.span, step=int(enc.step)) as sp:
+            journal: Optional[FlushJournal] = None
+            with span("ckpt.flush.partial"):
+                man.strategy = plan.strategy
+                man.files = dict(plan.files)
+                man.placement = placement_from_plan(plan)
+                if self.cfg.resumable_flushes:
+                    # commit the write set *before* the first byte: a
+                    # flush_partial manifest (full columnar placement +
+                    # file sizes) plus the extent journal is everything
+                    # resume_flushes() needs after any interruption.
+                    # fresh=True: a journal left by a previous incarnation
+                    # of this step describes *different bytes* and must
+                    # never skip writes here.
+                    man.status = "flush_partial"
+                    self._write_manifest_pfs(man)
+                    journal = FlushJournal(self._journal_path(enc.step), fresh=True)
             try:
-                self._gc()
-            except Exception:
-                log.exception("gc failed")
+                res = self.executor.execute(
+                    plan, enc.step,
+                    cancel=job.token, limiter=self._limiter, journal=journal,
+                )
+            except FlushCancelled:
+                if job.superseded and self.cfg.resumable_flushes:
+                    # a superseded partial is dead, not resumable: newer
+                    # state already replaced it — mark it so resume skips it
+                    man.status = "superseded"
+                    self._write_manifest_pfs(man)
+                raise
+            with span("ckpt.flush.commit"):
+                man.status = "flush_done"
+                self._write_manifest_pfs(man)
+                self._notify_flush_done(enc.step)
+            # files the flush created on the PFS: data, manifest, journal
+            sp.set(bytes=res.bytes_written, extents=res.n_writes,
+                   files=len(plan.files) + 1 + (journal is not None),
+                   io_retries=res.io_retries)
+            if journal is not None:
+                journal.unlink()
+            if self.cfg.keep_n is not None:
+                try:
+                    self._gc()
+                except Exception:
+                    log.exception("gc failed")
         return res
 
     def resume_flushes(self) -> Dict[int, FlushResult]:
@@ -1713,20 +1752,26 @@ class CheckpointManager:
                 set(self.steps("pfs")) | set(self.steps("local")), reverse=True
             )
         errors: List[str] = []
-        for s in candidates:
-            with self._lock:
-                l0 = self._l0
-            if l0 is not None and l0.step == s:
-                tgt = self._decode_target(l0.manifest, target)
-                tree = deserialize_tree(l0.stream, l0.manifest.leaves, tgt)
-                tree = self._maybe_dequant(l0.manifest, tree, target)
-                return s, self._place(tree, sharding_fn)
-            for loader in (self._restore_from_pfs, self._restore_from_local):
-                try:
-                    tree = loader(s, target)
+        known = {} if step is None else {"step": int(step)}
+        with span("ckpt.restore", **known) as sp:
+            for s in candidates:
+                served = {} if known else {"step": int(s)}
+                with self._lock:
+                    l0 = self._l0
+                if l0 is not None and l0.step == s:
+                    tgt = self._decode_target(l0.manifest, target)
+                    tree = deserialize_tree(l0.stream, l0.manifest.leaves, tgt)
+                    tree = self._maybe_dequant(l0.manifest, tree, target)
+                    sp.set(level="l0", **served)
                     return s, self._place(tree, sharding_fn)
-                except Exception as e:
-                    errors.append(f"step {s} via {loader.__name__}: {e!r}")
+                for level, loader in (("pfs", self._restore_from_pfs),
+                                      ("local", self._restore_from_local)):
+                    try:
+                        tree = loader(s, target)
+                        sp.set(level=level, **served)
+                        return s, self._place(tree, sharding_fn)
+                    except Exception as e:
+                        errors.append(f"step {s} via {loader.__name__}: {e!r}")
         raise FileNotFoundError(
             "no restorable checkpoint found; attempts: " + "; ".join(errors[:8])
         )
@@ -1912,9 +1957,13 @@ class CheckpointManager:
         bad: List[int] = []
         if verify:
             expected = [man.ranks[int(r)].crc for r in sel.tolist()]
+            parent = current()  # the arrival hook runs on reader threads
 
             def on_request(i: int, buf: bytearray) -> None:
-                if crc32(buf) != expected[i]:
+                with span("ckpt.restore.crc", parent=parent,
+                          rank=int(sel[i]), bytes=len(buf)):
+                    ok = crc32(buf) == expected[i]
+                if not ok:
                     bad.append(int(sel[i]))  # list.append is atomic
 
         bufs, res = self.executor.execute_read_plan(

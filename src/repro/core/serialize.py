@@ -68,6 +68,7 @@ import jax
 
 from repro.core.cluster import ClusterSpec
 from repro.core.integrity import crc32
+from repro.core.spans import Span, span
 from repro.kernels.checksum.ref import digest_ref
 from repro.utils.treelib import flatten_with_names
 
@@ -381,7 +382,10 @@ def serialize_tree(
     equivalence tests prove the streams byte-identical.
     """
     named, _ = flatten_with_names(state)
-    arrs = [_leaf_to_np(leaf) for _, leaf in named]
+    with span("ckpt.save.gather", leaves=len(named)) as sp:
+        # device-to-host: np.asarray blocks on each leaf's copy
+        arrs = [_leaf_to_np(leaf) for _, leaf in named]
+        sp.set(bytes=sum(int(a.nbytes) for a in arrs))
     leaves: List[LeafEntry] = []
     off = 0
     for (name, _), arr in zip(named, arrs):
@@ -403,11 +407,12 @@ def serialize_tree(
         np.copyto(dst.view(arr.dtype).reshape(arr.shape), arr, casting="no")
 
     jobs = list(zip(leaves, arrs))
-    if pool is not None and len(jobs) > 1:
-        list(pool.map(copy_leaf, jobs))
-    else:
-        for j in jobs:
-            copy_leaf(j)
+    with span("ckpt.save.copy", bytes=off):
+        if pool is not None and len(jobs) > 1:
+            list(pool.map(copy_leaf, jobs))
+        else:
+            for j in jobs:
+                copy_leaf(j)
     return memoryview(buf).toreadonly(), leaves
 
 
@@ -1037,6 +1042,11 @@ def _run_grouped(pool: Optional[Executor], fn, jobs: List, groups: int = 128):
     return out
 
 
+def _rank_crc(blob: Buffer, rank: int, parent: Span) -> int:
+    with span("ckpt.save.crc", parent=parent, rank=rank, bytes=len(blob)):
+        return crc32(blob)
+
+
 def encode_state(
     step: int,
     state: Any,
@@ -1103,14 +1113,16 @@ def encode_state(
             b, cols = encode_blob_reference(raw, codec, base_seg, impl=impl), None
         entry = RankEntry(
             rank=r, offset=off, raw_size=size, stored_size=len(b),
-            crc=crc32(b),
+            crc=_rank_crc(b, r, ranks_span),
         )
         if rank_sink is not None:
-            rank_sink(r, b)
+            with span("ckpt.save.l1_write", parent=ranks_span, rank=r, bytes=len(b)):
+                rank_sink(r, b)
         return b, entry, cols
 
     jobs = [(r, off, size) for r, (off, size) in enumerate(parts)]
-    results = _run_grouped(pool, encode_rank, jobs)
+    with span("ckpt.save.ranks", ranks=len(jobs)) as ranks_span:
+        results = _run_grouped(pool, encode_rank, jobs)
     blobs = [b for b, _, _ in results]
     ranks = [e for _, e, _ in results]
     man = Manifest(
@@ -1195,14 +1207,16 @@ def encode_state_staged(
             b, cols = encode_rank_chunks(raw, None, "zstd+delta", chunk_size, impl)
         entry = RankEntry(
             rank=r, offset=off, raw_size=size, stored_size=len(b),
-            crc=crc32(b),
+            crc=_rank_crc(b, r, ranks_span),
         )
         if rank_sink is not None:
-            rank_sink(r, b)
+            with span("ckpt.save.l1_write", parent=ranks_span, rank=r, bytes=len(b)):
+                rank_sink(r, b)
         return b, entry, cols
 
     jobs = [(r, off, size) for r, (off, size) in enumerate(parts)]
-    results = _run_grouped(pool, encode_rank, jobs)
+    with span("ckpt.save.ranks", ranks=len(jobs)) as ranks_span:
+        results = _run_grouped(pool, encode_rank, jobs)
     table = ChunkTable.from_rank_lists([c for _, _, c in results])
     table.digest = digests
     man = Manifest(
@@ -1327,9 +1341,10 @@ def decode_state(
     verify: bool = True,
     pool: Optional[Executor] = None,
 ) -> Any:
-    stream = decode_stream(
-        manifest, blobs, base_stream=base_stream, verify=verify, pool=pool
-    )
-    if len(stream) != manifest.total_raw_bytes:
-        raise IOError("reassembled stream has wrong size")
-    return deserialize_tree(stream, manifest.leaves, target)
+    with span("ckpt.restore.decode", bytes=int(manifest.total_raw_bytes)):
+        stream = decode_stream(
+            manifest, blobs, base_stream=base_stream, verify=verify, pool=pool
+        )
+        if len(stream) != manifest.total_raw_bytes:
+            raise IOError("reassembled stream has wrong size")
+        return deserialize_tree(stream, manifest.leaves, target)

@@ -76,7 +76,7 @@ from concurrent.futures import ThreadPoolExecutor, as_completed
 from concurrent.futures import wait as futures_wait
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -92,6 +92,7 @@ from repro.core.plan import (
 )
 from repro.core.faults import FaultPlan, inject_write
 from repro.core.serialize import Manifest, Placement
+from repro.core.spans import current, span
 
 
 class FlushCancelled(Exception):
@@ -893,16 +894,17 @@ class FlushJournal:
     def _flush_locked(self) -> None:
         if not self._buf:
             return
-        if self.pre_sync is not None:
-            self.pre_sync()  # data durability strictly before the claim
-        arr = np.asarray(self._buf, dtype="<i8")
-        with open(self.path, "ab") as f:
-            f.write(arr.tobytes())
-            f.flush()
-            try:
-                os.fsync(f.fileno())
-            except OSError:  # pragma: no cover - fs without fsync
-                pass
+        with span("ckpt.flush.journal", records=len(self._buf)):
+            if self.pre_sync is not None:
+                self.pre_sync()  # data durability strictly before the claim
+            arr = np.asarray(self._buf, dtype="<i8")
+            with open(self.path, "ab") as f:
+                f.write(arr.tobytes())
+                f.flush()
+                try:
+                    os.fsync(f.fileno())
+                except OSError:  # pragma: no cover - fs without fsync
+                    pass
         self._buf.clear()
 
     def unlink(self) -> None:
@@ -1307,7 +1309,21 @@ class RealExecutor:
             cancel=cancel, limiter=limiter, journal=journal,
         )
 
-    def _execute_columns(
+    def _execute_columns(self, *args: Any, **kw: Any) -> FlushResult:
+        """Shared column runner behind :meth:`execute` and
+        :meth:`execute_resume`: open+size the files, stream the rows
+        through the persistent pool, fsync on success.  ``ftruncate``
+        to an unchanged size preserves existing contents, so re-opening
+        a partially flushed step never clobbers resumed extents.
+        Arguments are :meth:`_write_columns`'s; the span it runs in
+        times ``FlushResult.duration``."""
+        with span("ckpt.flush.write") as sw:
+            res = self._write_columns(*args, **kw)
+            sw.set(bytes=res.bytes_written)
+        res.duration = sw.seconds
+        return res
+
+    def _write_columns(
         self,
         files: Dict[str, int],
         names: Sequence[str],
@@ -1321,23 +1337,20 @@ class RealExecutor:
         limiter: Optional[TokenBucket] = None,
         journal: Optional[FlushJournal] = None,
     ) -> FlushResult:
-        """Shared column runner behind :meth:`execute` and
-        :meth:`execute_resume`: open+size the files, stream the rows
-        through the persistent pool, fsync on success.  ``ftruncate``
-        to an unchanged size preserves existing contents, so re-opening
-        a partially flushed step never clobbers resumed extents."""
-        t0 = time.perf_counter()
+        """The body of :meth:`_execute_columns`."""
+        parent = current()  # of the extent spans on the pool threads
         sdir = self.step_dir(step)
-        sdir.mkdir(parents=True, exist_ok=True)
 
         # Pre-create + size every file (the metadata phase).
         fds: Dict[str, int] = {}
         try:
-            for fname, size in files.items():
-                path = sdir / fname
-                fd = os.open(str(path), os.O_CREAT | os.O_WRONLY, 0o644)
-                os.ftruncate(fd, size)
-                fds[fname] = fd
+            with span("ckpt.flush.open", files=len(files)):
+                sdir.mkdir(parents=True, exist_ok=True)
+                for fname, size in files.items():
+                    path = sdir / fname
+                    fd = os.open(str(path), os.O_CREAT | os.O_WRONLY, 0o644)
+                    os.ftruncate(fd, size)
+                    fds[fname] = fd
             if journal is not None:
                 # a journal record is a durability claim: fsync the data
                 # fds before any batch of records is persisted
@@ -1357,51 +1370,55 @@ class RealExecutor:
                     with lock:
                         total["skipped"] += size
                     return
-                if hook is not None:
-                    # fault-injection surface: materialize the item view
-                    # for this row only (never a whole-plan list)
-                    hook(WriteItem(backend=backend, file=names[fid],
-                                   file_offset=foff, size=size,
-                                   src_rank=src_rank, src_offset=soff,
-                                   round=rnd))
-                waited = (
-                    limiter.acquire(size, cancel=cancel)
-                    if limiter is not None else 0.0
-                )
-                # leader pulls from the source node's L1 file ("the
-                # send"); if the home node's copy is gone (node loss),
-                # the partner replica on node+1 — the same invariant
-                # restore uses — keeps the flush finishable
-                try:
-                    data = self.local.read_slice(
-                        home, step, src_rank, soff, size
+                with span("ckpt.flush.extent", parent=parent, bytes=size):
+                    if hook is not None:
+                        # fault-injection surface: materialize the item
+                        # view for this row only (never a whole-plan list)
+                        hook(WriteItem(backend=backend, file=names[fid],
+                                       file_offset=foff, size=size,
+                                       src_rank=src_rank, src_offset=soff,
+                                       round=rnd))
+                    waited = (
+                        limiter.acquire(size, cancel=cancel)
+                        if limiter is not None else 0.0
                     )
-                except OSError:
-                    partner = (home + 1) % max(1, self.local.n_nodes)
-                    data = self.local.read_slice(
-                        partner, step, src_rank, soff, size, partner=True
-                    )
-                if len(data) != size:
-                    raise IOError(
-                        f"short read: rank {src_rank} [{soff}:{soff + size})"
-                    )
+                    # leader pulls from the source node's L1 file ("the
+                    # send"); if the home node's copy is gone (node loss),
+                    # the partner replica on node+1 — the same invariant
+                    # restore uses — keeps the flush finishable
+                    with span("ckpt.flush.l1_read"):
+                        try:
+                            data = self.local.read_slice(
+                                home, step, src_rank, soff, size
+                            )
+                        except OSError:
+                            partner = (home + 1) % max(1, self.local.n_nodes)
+                            data = self.local.read_slice(
+                                partner, step, src_rank, soff, size, partner=True
+                            )
+                    if len(data) != size:
+                        raise IOError(
+                            f"short read: rank {src_rank} [{soff}:{soff + size})"
+                        )
 
-                def attempt() -> None:
-                    # the injection + pwrite is the retried unit: a torn
-                    # write's re-attempt rewrites the full extent
-                    inject_write(
-                        self.faults, "pfs", f"{names[fid]}@{foff}", data,
-                        lambda buf: os.pwrite(fds[names[fid]], buf, foff),
-                    )
+                    def attempt() -> None:
+                        # the injection + pwrite is the retried unit: a
+                        # torn write's re-attempt rewrites the full extent
+                        inject_write(
+                            self.faults, "pfs", f"{names[fid]}@{foff}", data,
+                            lambda buf: os.pwrite(fds[names[fid]], buf, foff),
+                        )
 
-                if self.retry is not None:
-                    self.retry.run(
-                        attempt, cancel=cancel, stats=retry_stats, domain="pfs"
-                    )
-                else:
-                    attempt()
-                if journal is not None:
-                    journal.record(fid, foff, size)
+                    with span("ckpt.flush.pwrite"):
+                        if self.retry is not None:
+                            self.retry.run(
+                                attempt, cancel=cancel, stats=retry_stats,
+                                domain="pfs",
+                            )
+                        else:
+                            attempt()
+                    if journal is not None:
+                        journal.record(fid, foff, size)
                 with lock:
                     total["bytes"] += size
                     total["writes"] += 1
@@ -1425,11 +1442,12 @@ class RealExecutor:
             else:
                 self._run_rows(rows, do_write, workers)
 
-            for fd in fds.values():
-                os.fsync(fd)
+            with span("ckpt.flush.fsync"):
+                for fd in fds.values():
+                    os.fsync(fd)
             return FlushResult(
                 step=step,
-                duration=time.perf_counter() - t0,
+                duration=0.0,  # set from the span by _execute_columns
                 bytes_written=total["bytes"],
                 n_writes=total["writes"],
                 bytes_skipped=total["skipped"],
@@ -1594,7 +1612,18 @@ class RealExecutor:
         success claims the destination buffer, the loser is discarded
         (see :class:`HedgePolicy`).
         """
-        t0 = time.perf_counter()
+        with span("ckpt.restore.read") as sp:
+            bufs, res = self._read_plan(rp, step, on_request, hedge)
+            sp.set(bytes=res.bytes_read, reads=res.n_reads, readers=res.n_readers)
+        res.duration = sp.seconds
+        return bufs, res
+
+    def _read_plan(
+        self, rp: ReadPlan, step: int,
+        on_request: Optional[Callable[[int, bytearray], None]],
+        hedge: Optional[HedgePolicy],
+    ) -> Tuple[List[bytearray], ReadResult]:
+        """The body of :meth:`execute_read_plan`."""
         sdir = self.step_dir(step)
         bufs = [bytearray(int(n)) for n in rp.req_size.tolist()]
         r = rp.reads
@@ -1606,8 +1635,7 @@ class RealExecutor:
                 on_request(q, bufs[q])
         if not len(r):
             return bufs, ReadResult(
-                step=step, duration=time.perf_counter() - t0,
-                bytes_read=0, n_reads=0, n_readers=0,
+                step=step, duration=0.0, bytes_read=0, n_reads=0, n_readers=0,
             )
         fds: Dict[int, int] = {}
         lock = threading.Lock()
@@ -1800,7 +1828,7 @@ class RealExecutor:
                     th.join()
             return bufs, ReadResult(
                 step=step,
-                duration=time.perf_counter() - t0,
+                duration=0.0,  # set from the span by execute_read_plan
                 bytes_read=total["bytes"],
                 n_reads=total["reads"],
                 n_readers=n_readers,
