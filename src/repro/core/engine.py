@@ -121,10 +121,12 @@ from repro.core.serialize import (
     decode_blob_reference,
     decode_chunk_into,
     decode_state,
+    decode_state_in_place,
     decode_stream,
     default_codec_impl,
     deserialize_tree,
     encode_state,
+    stored_is_stream,
     _run_grouped,
 )
 from repro.core.admission import AdmissionController
@@ -1923,7 +1925,8 @@ class CheckpointManager:
     def _read_blobs_pfs(
         self, man: Manifest, step: int, ranks: Optional[List[int]] = None,
         *, record: bool = True, verify: bool = False,
-    ) -> Dict[int, bytearray]:
+        into: Optional[np.ndarray] = None,
+    ) -> Dict[int, Buffer]:
         """Fetch stored rank blobs through ONE aggregated :class:`ReadPlan`.
 
         The read-side twin of the flush: the manifest's placement is
@@ -1940,6 +1943,11 @@ class CheckpointManager:
         preads instead of running as a serial pass in ``decode_state``
         afterwards.  All mismatches are collected and raised together
         after the plan drains.
+
+        ``into`` (a ``uint8`` stream buffer, for a manifest for which
+        :func:`~repro.core.serialize.stored_is_stream` holds and with no
+        hedge armed) makes every blob land in its stream slice: the
+        returned blobs are views of it.
         """
         layout = man.file_layout()
         offsets = man.stored_offsets()
@@ -1959,15 +1967,23 @@ class CheckpointManager:
             expected = [man.ranks[int(r)].crc for r in sel.tolist()]
             parent = current()  # the arrival hook runs on reader threads
 
-            def on_request(i: int, buf: bytearray) -> None:
+            def on_request(i: int, buf: Buffer) -> None:
                 with span("ckpt.restore.crc", parent=parent,
                           rank=int(sel[i]), bytes=len(buf)):
                     ok = crc32(buf) == expected[i]
                 if not ok:
                     bad.append(int(sel[i]))  # list.append is atomic
 
+        dsts = None
+        if into is not None:
+            stream = memoryview(into)
+            dsts = [
+                stream[e.offset : e.offset + e.raw_size]
+                for e in (man.ranks[int(r)] for r in sel.tolist())
+            ]
         bufs, res = self.executor.execute_read_plan(
-            rp, step, on_request=on_request, hedge=self._hedge_policy(man, step)
+            rp, step, on_request=on_request, hedge=self._hedge_policy(man, step),
+            into=dsts,
         )
         if record:  # the scrub passes False so restore telemetry survives
             self.last_read_result = res
@@ -2003,6 +2019,13 @@ class CheckpointManager:
         man = self._manifest_pfs(step)
         self._check_delta_base(man)
         verify = self.cfg.verify_on_restore
+        if stored_is_stream(man) and self._hedge_policy(man, step) is None:
+            # the stored blobs are the stream: read them into it in place
+            # and hand out the leaves as views of it
+            out = np.empty(man.total_raw_bytes, np.uint8)
+            self._read_blobs_pfs(man, step, verify=verify, into=out)
+            tree = decode_state_in_place(man, out, self._decode_target(man, target))
+            return self._maybe_dequant(man, tree, target)
         by_rank = self._read_blobs_pfs(man, step, verify=verify)
         blobs = [by_rank[r] for r in range(man.world_size)]
         base_stream = (

@@ -61,7 +61,7 @@ import threading
 import zlib as _zlib
 from concurrent.futures import Executor
 from dataclasses import asdict, dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 import jax
@@ -421,8 +421,21 @@ def deserialize_tree(stream: Buffer, leaves: Sequence[LeafEntry], target: Any) -
 
     `target` may contain arrays or jax.ShapeDtypeStructs; only the
     structure is used.  Leaf order must match the saved order (name
-    mismatches raise).
+    mismatches raise).  Every leaf is a copy: the stream may be shared
+    (the L0 twin's live save buffer, an L1 blob), and mutating a
+    restored tree must never reach it.
     """
+
+    def leaf(entry: LeafEntry) -> np.ndarray:
+        buf = stream[entry.offset : entry.offset + entry.size]
+        return np.frombuffer(buf, dtype=np.dtype(entry.dtype)).reshape(entry.shape).copy()
+
+    return _fill_target(target, leaves, leaf)
+
+
+def _fill_target(
+    target: Any, leaves: Sequence[LeafEntry], leaf: Callable[[LeafEntry], np.ndarray]
+) -> Any:
     named, treedef = flatten_with_names(target)
     if len(named) != len(leaves):
         raise ValueError(
@@ -432,9 +445,7 @@ def deserialize_tree(stream: Buffer, leaves: Sequence[LeafEntry], target: Any) -
     for (name, _), entry in zip(named, leaves):
         if name != entry.name:
             raise ValueError(f"leaf mismatch: target {name!r} vs saved {entry.name!r}")
-        buf = stream[entry.offset : entry.offset + entry.size]
-        arr = np.frombuffer(buf, dtype=np.dtype(entry.dtype)).reshape(entry.shape)
-        vals.append(arr.copy())
+        vals.append(leaf(entry))
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 
@@ -1348,3 +1359,40 @@ def decode_state(
         if len(stream) != manifest.total_raw_bytes:
             raise IOError("reassembled stream has wrong size")
         return deserialize_tree(stream, manifest.leaves, target)
+
+
+def stored_is_stream(manifest: Manifest) -> bool:
+    """Whether the stored blobs, each at its rank's stream offset, are
+    the raw stream itself: codec ``none``, not chunk-framed, no delta
+    base, every blob stored at its raw size, the ranks tiling the
+    stream.  Such blobs can be read straight into their stream slices
+    and decoded by :func:`decode_state_in_place`."""
+    if manifest.codec != "none" or manifest.chunks is not None:
+        return False
+    if manifest.base_step is not None:
+        return False
+    end = 0
+    for r in sorted(manifest.ranks, key=lambda r: r.offset):
+        if r.offset != end or r.stored_size != r.raw_size:
+            return False
+        end += r.raw_size
+    return end == manifest.total_raw_bytes
+
+
+def decode_state_in_place(manifest: Manifest, stream: np.ndarray, target: Any) -> Any:
+    """:func:`decode_state` of a manifest for which
+    :func:`stored_is_stream` holds, whose verified blobs were read into
+    ``stream`` (``uint8``), which the caller hands over.  Each leaf is a
+    writable view of its own disjoint slice of ``stream``, so the decode
+    copies nothing, and ``stream`` lives while any leaf does; a leaf the
+    stream leaves misaligned for its dtype is copied."""
+
+    def leaf(entry: LeafEntry) -> np.ndarray:
+        raw = stream[entry.offset : entry.offset + entry.size]
+        arr = raw.view(np.dtype(entry.dtype)).reshape(entry.shape)
+        return arr if arr.flags.aligned else arr.copy()
+
+    with span("ckpt.restore.decode", bytes=int(manifest.total_raw_bytes)):
+        if len(stream) != manifest.total_raw_bytes:
+            raise IOError("reassembled stream has wrong size")
+        return _fill_target(target, manifest.leaves, leaf)

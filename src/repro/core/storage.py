@@ -76,7 +76,7 @@ from concurrent.futures import ThreadPoolExecutor, as_completed
 from concurrent.futures import wait as futures_wait
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -91,7 +91,7 @@ from repro.core.plan import (
     merge_intervals,
 )
 from repro.core.faults import FaultPlan, inject_write
-from repro.core.serialize import Manifest, Placement
+from repro.core.serialize import Buffer, Manifest, Placement
 from repro.core.spans import current, span
 
 
@@ -1142,6 +1142,25 @@ class ReadResult:
     # deadline, and how many beat their primary to the buffer.
     hedges_issued: int = 0
     hedge_wins: int = 0
+    # the reads landed in the caller's buffers (``execute_read_plan``'s
+    # ``into``), not in buffers the executor allocated
+    in_place: bool = False
+
+
+def _preadv_full(fd: int, dst: memoryview, offset: int) -> int:
+    """Read ``len(dst)`` bytes at ``offset`` straight into ``dst``.
+
+    One ``preadv`` may return fewer bytes than asked (Linux caps a call
+    just under 2 GiB), so it loops while a call makes progress.  Returns
+    the bytes read: fewer than ``len(dst)`` only at end of file.
+    """
+    got, n = 0, len(dst)
+    while got < n:
+        k = os.preadv(fd, [dst[got:]], offset + got)
+        if k <= 0:
+            break
+        got += k
+    return got
 
 
 @dataclass
@@ -1586,9 +1605,10 @@ class RealExecutor:
 
     def execute_read_plan(
         self, rp: ReadPlan, step: int,
-        *, on_request: Optional[Callable[[int, bytearray], None]] = None,
+        *, on_request: Optional[Callable[[int, Buffer], None]] = None,
         hedge: Optional[HedgePolicy] = None,
-    ) -> Tuple[List[bytearray], ReadResult]:
+        into: Optional[Sequence[Buffer]] = None,
+    ) -> Tuple[List[Buffer], ReadResult]:
         """Run a :class:`ReadPlan` as ranged ``pread``s via the thread pool.
 
         Returns one buffer per request (``rp.req_size[i]`` bytes each)
@@ -1611,21 +1631,40 @@ class RealExecutor:
         latency-quantile deadline through ``hedge.alt_read`` — first
         success claims the destination buffer, the loser is discarded
         (see :class:`HedgePolicy`).
+
+        ``into``, when given and no hedge is armed, holds the caller's
+        destinations: one writable buffer per request, ``rp.req_size[i]``
+        bytes each.  Every read then lands in its slice by ``preadv``;
+        nothing is allocated or copied, and the returned buffers (and
+        those ``on_request`` sees) are byte views of the caller's.  A
+        retry rereads into the same slice.  With a hedge armed ``into``
+        is ignored (``ReadResult.in_place`` tells which ran).
         """
         with span("ckpt.restore.read") as sp:
-            bufs, res = self._read_plan(rp, step, on_request, hedge)
-            sp.set(bytes=res.bytes_read, reads=res.n_reads, readers=res.n_readers)
+            bufs, res = self._read_plan(
+                rp, step, on_request, hedge, into if hedge is None else None
+            )
+            sp.set(bytes=res.bytes_read, reads=res.n_reads, readers=res.n_readers,
+                   in_place=int(res.in_place))
         res.duration = sp.seconds
         return bufs, res
 
     def _read_plan(
         self, rp: ReadPlan, step: int,
-        on_request: Optional[Callable[[int, bytearray], None]],
+        on_request: Optional[Callable[[int, Buffer], None]],
         hedge: Optional[HedgePolicy],
-    ) -> Tuple[List[bytearray], ReadResult]:
+        into: Optional[Sequence[Buffer]],
+    ) -> Tuple[List[Buffer], ReadResult]:
         """The body of :meth:`execute_read_plan`."""
         sdir = self.step_dir(step)
-        bufs = [bytearray(int(n)) for n in rp.req_size.tolist()]
+        in_place = into is not None
+        if in_place:
+            # byte views: slicing one never copies, unlike a bytearray
+            bufs: List[Buffer] = [memoryview(b).cast("B") for b in into]
+            if [len(b) for b in bufs] != rp.req_size.tolist():
+                raise ValueError("read destinations do not match the plan's requests")
+        else:
+            bufs = [bytearray(int(n)) for n in rp.req_size.tolist()]
         r = rp.reads
         remaining = np.bincount(
             r.dst_req, minlength=rp.n_requests
@@ -1636,6 +1675,7 @@ class RealExecutor:
         if not len(r):
             return bufs, ReadResult(
                 step=step, duration=0.0, bytes_read=0, n_reads=0, n_readers=0,
+                in_place=in_place,
             )
         fds: Dict[int, int] = {}
         lock = threading.Lock()
@@ -1676,7 +1716,8 @@ class RealExecutor:
                     return False
                 claimed[i] = True
                 finished[i] = True
-            bufs[req][doff : doff + size] = data
+            if data is not None:  # None: the read landed in place
+                bufs[req][doff : doff + size] = data
             with lock:
                 total["bytes"] += size
                 total["reads"] += 1
@@ -1700,11 +1741,13 @@ class RealExecutor:
                     return
                 starts[i] = time.monotonic()
 
-            def attempt() -> bytes:
+            def attempt() -> Union[bytes, int]:
                 if self.faults is not None:
                     self.faults.on_op(
                         "pfs", "read", rp.file_names[fid], node=reader
                     )
+                if in_place:
+                    return _preadv_full(fds[fid], bufs[req][doff : doff + size], foff)
                 return os.pread(fds[fid], size, foff)
 
             try:
@@ -1720,7 +1763,8 @@ class RealExecutor:
                         return  # the hedge already delivered this extent
                 raise
             dt = time.monotonic() - starts[i]
-            if len(data) != size:
+            got = data if in_place else len(data)
+            if got != size:
                 with lock:
                     finished[i] = True
                 raise IOError(
@@ -1731,7 +1775,7 @@ class RealExecutor:
                 health.note_latency(f"reader:n{reader}", dt)
             with lock:
                 lat_samples.append(dt)
-            complete(i, row, data, won_hedge=False)
+            complete(i, row, None if in_place else data, won_hedge=False)
 
         def run_hedge(i: int, row) -> None:
             fid, foff, size, req, doff, reader = row
@@ -1836,6 +1880,7 @@ class RealExecutor:
                 io_giveups=retry_stats["giveups"],
                 hedges_issued=total["hedges"],
                 hedge_wins=total["hedge_wins"],
+                in_place=in_place,
             )
         finally:
             if stragglers:

@@ -4,6 +4,8 @@ The read-side twin of tests/test_plan_arrays.py — every check compares
 the array program against a brute-force byte-level simulation, and the
 executor tests run against real files written by a real flush.
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -270,6 +272,144 @@ def test_executor_reads_real_flush(tmp_path, strategy):
         node = r // man.procs_per_node
         assert by_rank[r] == mgr.local.read_blob(node, 2, r)
     assert mgr.last_read_result.bytes_read == man.total_stored_bytes
+    mgr.close()
+
+
+def _flushed_plan(tmp_path, strategy="stripe_aligned"):
+    """A real flush of a 3x2 save and the aggregated plan reading every
+    rank blob back onto 3 readers.  Returns (manager, ReadPlan)."""
+    import jax.numpy as jnp
+
+    state = {"w": jnp.arange(60_000, dtype=jnp.float32),
+             "b": jnp.ones((1001,), jnp.int8)}
+    mgr = CheckpointManager(
+        CheckpointConfig(root=str(tmp_path), cluster=theta_like(3, 2),
+                         strategy=strategy, async_flush=False)
+    )
+    mgr.save(2, state)
+    assert not mgr.flush_errors
+    man = mgr._manifest_pfs(2)
+    sizes = np.asarray([r.stored_size for r in man.ranks], np.int64)
+    rp = build_read_plan(man.file_layout(), man.stored_offsets()[: len(sizes)], sizes,
+                         assign_readers(sizes, 3))
+    return mgr, rp
+
+
+def _destinations(rp):
+    """One stream buffer and a view of it per request, in request order."""
+    out = np.empty(int(rp.req_size.sum()), np.uint8)
+    ends = np.cumsum(rp.req_size).tolist()
+    view = memoryview(out)
+    return out, [view[e - int(n) : e] for e, n in zip(ends, rp.req_size.tolist())]
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_read_into_caller_buffers_matches_allocating_path(tmp_path, strategy):
+    mgr, rp = _flushed_plan(tmp_path, strategy)
+    want, res_alloc = mgr.executor.execute_read_plan(rp, 2)
+    out, dsts = _destinations(rp)
+    seen = {}
+    got, res = mgr.executor.execute_read_plan(
+        rp, 2, into=dsts, on_request=lambda i, buf: seen.__setitem__(i, buf)
+    )
+    mgr.close()
+    assert res.in_place and not res_alloc.in_place
+    assert (res.bytes_read, res.n_reads) == (res_alloc.bytes_read, res_alloc.n_reads)
+    assert out.tobytes() == b"".join(bytes(b) for b in want)
+    assert sorted(seen) == list(range(rp.n_requests))
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert bytes(g) == bytes(w)
+        assert g.obj is out and seen[i].obj is out  # views, nothing copied
+
+
+def test_read_into_rejects_destinations_of_the_wrong_size(tmp_path):
+    mgr, rp = _flushed_plan(tmp_path)
+    _, dsts = _destinations(rp)
+    dsts[0] = dsts[0][1:]
+    with pytest.raises(ValueError, match="destinations"):
+        mgr.executor.execute_read_plan(rp, 2, into=dsts)
+    mgr.close()
+
+
+def test_read_into_ignored_with_a_hedge_armed(tmp_path):
+    from repro.core.storage import HedgePolicy
+
+    mgr, rp = _flushed_plan(tmp_path)
+    want, _ = mgr.executor.execute_read_plan(rp, 2)
+    out, dsts = _destinations(rp)
+    out[:] = 0xAB
+    got, res = mgr.executor.execute_read_plan(
+        rp, 2, into=dsts, hedge=HedgePolicy(alt_read=lambda *a: None)
+    )
+    mgr.close()
+    assert not res.in_place
+    assert [bytes(b) for b in got] == [bytes(b) for b in want]
+    assert (out == 0xAB).all()  # the caller's buffer was not written
+
+
+def test_read_into_retry_rereads_the_same_slice(tmp_path, monkeypatch):
+    """A transient error after a partial read into the slice: the retry
+    rereads the whole slice, and the result is exact."""
+    import errno
+    import threading
+
+    from repro.core import storage
+    from repro.core.storage import RealExecutor, RetryPolicy
+
+    mgr, rp = _flushed_plan(tmp_path)
+    want, _ = mgr.executor.execute_read_plan(rp, 2)
+    ex = RealExecutor(mgr.pfs_dir, mgr.local,
+                      retry=RetryPolicy(base_delay=0.001, seed=0))
+    real, lock, failed = os.preadv, threading.Lock(), []
+
+    def flaky(fd, bufs, offset):
+        with lock:
+            first = not failed
+            failed.append(offset)
+        if first:
+            half = len(bufs[0]) // 2
+            bufs[0][:half] = b"\xee" * half  # garbage, then the error
+            raise OSError(errno.EIO, "injected transient read error")
+        return real(fd, bufs, offset)
+
+    monkeypatch.setattr(storage.os, "preadv", flaky)
+    out, dsts = _destinations(rp)
+    got, res = ex.execute_read_plan(rp, 2, into=dsts)
+    ex.close()
+    mgr.close()
+    assert res.in_place and res.io_retries == 1
+    assert out.tobytes() == b"".join(bytes(b) for b in want)
+
+
+def test_read_into_loops_over_short_preadv_returns(tmp_path, monkeypatch):
+    """``preadv`` may return fewer bytes than asked (as it does past 2
+    GiB): the read loops until the slice is full."""
+    from repro.core import storage
+
+    mgr, rp = _flushed_plan(tmp_path)
+    want, _ = mgr.executor.execute_read_plan(rp, 2)
+    real, calls = os.preadv, []
+
+    def short(fd, bufs, offset):
+        calls.append(offset)
+        return real(fd, [bufs[0][:4096]], offset)  # at most 4 KiB a call
+
+    monkeypatch.setattr(storage.os, "preadv", short)
+    out, dsts = _destinations(rp)
+    _, res = mgr.executor.execute_read_plan(rp, 2, into=dsts)
+    mgr.close()
+    assert len(calls) > 2 * res.n_reads
+    assert out.tobytes() == b"".join(bytes(b) for b in want)
+
+
+def test_read_into_truncated_file_raises_short_read(tmp_path):
+    mgr, rp = _flushed_plan(tmp_path)
+    sdir = mgr.executor.step_dir(2)
+    for name in rp.file_names:
+        os.truncate(sdir / name, os.path.getsize(sdir / name) // 2)
+    _, dsts = _destinations(rp)
+    with pytest.raises(IOError, match="short PFS read"):
+        mgr.executor.execute_read_plan(rp, 2, into=dsts)
     mgr.close()
 
 

@@ -165,6 +165,7 @@ def test_save_counts(traced):
     assert restore.attrs["level"] == "pfs"
     read = by_name(recs, "ckpt.restore.read")[0]
     assert read.attrs["bytes"] == traced["read"].bytes_read
+    assert read.attrs["in_place"] == 1  # codec none: read into the stream
 
 
 def test_every_span_once_in_the_xspace(traced):
