@@ -3,7 +3,13 @@
 Everything that belongs to one cell is found by name:
 
 * the configuration: the file its ``configs`` entry names, with its
-  plain reference at ``bench/refs/<reference>.py``;
+  plain reference at ``bench/refs/<reference>.py``.  The file's published
+  keys, its ``program`` fields of the program's ``ModelConfig`` (experts,
+  a window or layer pattern, ...) and its ``mesh`` layout (``{"model":
+  m}`` for a four-chip cell that splits experts or heads) build the job
+  (``bench/job.py``); the reference makes the weights, follows the first
+  steps for ``correct``, and may count its own FLOPs a token
+  (``train_flops_per_token``, else ``bench/flops.py``'s dense count);
 * the traffic: ``bench/traffic/<traffic>.json``, whose ``kind`` names
   the generator ``bench/mixes/<kind>.py``;
 * the limits of the comparison that decides ``correct``:

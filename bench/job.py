@@ -1,11 +1,26 @@
 """One training job of the program under test, built from a cell's files.
 
-The model configuration comes from ``bench/configs/<config>.json`` laid
-over the program's registry entry; the weights, the optimizer state and
-every batch are made here from ``--seed``, on the device, by the
-configuration's plain reference module (``bench/refs/<reference>.py``).
-The train step is the program's own (``make_train_step``), compiled
-ahead of time for the cell's shapes.
+A configuration is its file, ``bench/configs/<config>.json``, and its
+plain reference, ``bench/refs/<reference>.py``.  The file holds:
+
+* ``registry``: the program's registry entry it starts from;
+* published keys (``hidden_size``, ``num_hidden_layers``, ...): those in
+  ``HF_KEYS`` are laid over the entry; the others are descriptive for the
+  program and are read by the reference, so ``build``'s check that the
+  reference's parameter layout is the program's ties the two together;
+* ``program`` (optional): fields of the program's ``ModelConfig`` the
+  published keys do not name (``moe``, ``window``, ``block_pattern``,
+  ``chunk_q``, ...), laid over the entry after them; see ``model_config``;
+* ``mesh`` (optional): ``{"model": m}`` puts ``m`` chips of the cell on
+  the ``model`` axis (experts, heads); the rest go to ``data``;
+* ``optimizer`` and ``checkpoint``: the AdamW settings and the checkpoint
+  manager's.
+
+The reference makes the weights, the optimizer state and every batch
+from ``--seed``, on the device, and may define
+``train_flops_per_token(cfg, seq_len)`` to count its own FLOPs (see
+``bench/flops.py``).  The train step is the program's own
+(``make_train_step``), compiled ahead of time for the cell's shapes.
 """
 from __future__ import annotations
 
@@ -51,8 +66,49 @@ def reference(config: Dict[str, Any]):
 
 
 def model_config(config: Dict[str, Any]) -> ModelConfig:
+    """The registry entry with the file's ``HF_KEYS`` laid over it, then
+    its ``program`` fields.  A ``program`` field whose entry value is a
+    dataclass (``moe``) is given as an object and laid over that value;
+    a JSON list becomes a tuple.  Raises ``ValueError`` for a field the
+    dataclass lacks, an object where the entry holds no dataclass, or a
+    field that a published key sets too."""
     over = {f: config[k] for k, f in HF_KEYS.items() if k in config}
-    return get_config(config["registry"]).replace(**over)
+    base = get_config(config["registry"])
+    for name, value in config.get("program", {}).items():
+        if name in over:
+            raise ValueError(f"program field {name!r} is set by a published key too")
+        over[name] = _field_value(base, name, value, "program")
+    return base.replace(**over)
+
+
+def _field_value(obj: Any, name: str, value: Any, where: str) -> Any:
+    """``value`` as the new value of dataclass ``obj``'s field ``name``."""
+    if name not in {f.name for f in dataclasses.fields(obj)}:
+        raise ValueError(f"{where} field {name!r}: {type(obj).__name__} has no such field")
+    if isinstance(value, list):
+        return tuple(value)
+    if not isinstance(value, dict):
+        return value
+    inner = getattr(obj, name)
+    if not dataclasses.is_dataclass(inner):
+        raise ValueError(f"{where} field {name!r} is an object, but the registry "
+                         f"entry holds {inner!r}")
+    return dataclasses.replace(inner, **{
+        k: _field_value(inner, k, v, f"{where}.{name}") for k, v in value.items()})
+
+
+def make_mesh(config: Dict[str, Any], devices: List[Any]) -> Any:
+    """A ``(chips // m, m)`` mesh over ``("data", "model")``, ``m`` from the
+    file's ``"mesh": {"model": m}`` (1 without it)."""
+    layout = dict(config.get("mesh", {}))
+    m = layout.pop("model", 1)
+    if layout:
+        raise ValueError(f"mesh keys {sorted(layout)}: only 'model' is known")
+    n = len(devices)
+    if not isinstance(m, int) or m < 1 or n % m:
+        raise ValueError(f"mesh model={m!r} does not divide the cell's {n} chips")
+    return jax.make_mesh((n // m, m), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
 
 
 def key_from_seed(seed: int, stream: int) -> jax.Array:
@@ -132,8 +188,7 @@ def build(config: Dict[str, Any], traffic: Dict[str, Any], seed: int,
     mcfg = model_config(config)
     ref = reference(config)
     model = get_model(mcfg)
-    mesh = jax.make_mesh((len(devices), 1), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2, devices=devices)
+    mesh = make_mesh(config, devices)
     seq, gb = traffic["seq_len"], traffic["global_batch"]
     opt = dict(config["optimizer"])
     tcfg = TrainConfig(opt=OptConfig(**opt))

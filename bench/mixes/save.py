@@ -24,7 +24,7 @@ import jax
 import numpy as np
 
 from bench import checks, ckpt
-from bench.flops import train_flops_per_token
+from bench.flops import flops_per_token
 from bench.job import build, device_digest, first_steps, reference_readings
 from bench.trace import span
 from repro.launch.train import place_state
@@ -134,7 +134,7 @@ def run(ctx):
         "window_s": t_end - t_start,
         "steps": steps,
         "tokens": steps * job.tokens_per_step,
-        "flops_per_token": train_flops_per_token(config, job.seq_len),
+        "flops_per_token": flops_per_token(config, job.ref, job.seq_len),
         "saves": records,
         "peak_bytes": mem["peak"],
         "bytes_limit": mem["limit"],
