@@ -10,6 +10,8 @@ Everything that belongs to one cell is found by name:
   (``bench/job.py``); the reference makes the weights, follows the first
   steps for ``correct``, and may count its own FLOPs a token
   (``train_flops_per_token``, else ``bench/flops.py``'s dense count);
+  its ``checkpoint`` block sets the manager and whether L1 is kept on
+  the disk or in host RAM (``bench/ckpt.py``);
 * the traffic: ``bench/traffic/<traffic>.json``, whose ``kind`` names
   the generator ``bench/mixes/<kind>.py``;
 * the limits of the comparison that decides ``correct``:
@@ -33,6 +35,7 @@ from typing import Any, Callable, Dict, List, Optional
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 RUNS = BENCH / ".runs"  # checkpoints and traces of the current run
+SPACE_MARGIN = 1.1  # free space asked for a level, over the state's bytes
 
 
 class BenchError(SystemExit):
@@ -116,6 +119,7 @@ class Context:
     tracer: Any
     t_start: float
     ckpt_root: Path
+    l1_ram: Optional[Path] = None  # the run's L1 directory in RAM, if it has one
     setup_s: Optional[float] = None
     checks: List = field(default_factory=list)
     marks: List = field(default_factory=list)
@@ -131,16 +135,37 @@ class Context:
     def check(self, name: str, value: float, limit: float) -> None:
         self.checks.append((name, float(value), float(limit)))
 
-    def require_disk(self, need_bytes: float) -> None:
-        free = shutil.disk_usage(self.ckpt_root).free
-        if free < need_bytes:
-            raise BenchError(f"disk holds {free / 1e9:.1f} GB free, the cell's "
-                             f"checkpoints need {need_bytes / 1e9:.1f} GB")
+    def require_space(self, state_bytes: float, saves: int) -> None:
+        """Room for ``saves`` saves of a state of ``state_bytes`` on each
+        level, checked before anything is written."""
+        self.require_disk(state_bytes, saves)
+        self.require_ram(state_bytes, saves)
+
+    def require_disk(self, state_bytes: float, saves: int) -> None:
+        """The levels on the disk: the PFS, and L1 when it is there."""
+        from bench.ckpt import levels_on_disk
+
+        levels = levels_on_disk(self.cell.config)
+        _require_free(self.ckpt_root, SPACE_MARGIN * state_bytes * saves * len(levels),
+                      f"disk ({' + '.join(levels)})")
+
+    def require_ram(self, state_bytes: float, saves: int) -> None:
+        """An L1 in RAM, under its RAM root."""
+        if self.l1_ram is not None:
+            _require_free(self.l1_ram, SPACE_MARGIN * state_bytes * saves,
+                          f"RAM root {self.l1_ram.parent} (l1)")
 
     def peak_bytes(self) -> Dict[str, int]:
         stats = [d.memory_stats() or {} for d in self.devices]
         return {"peak": max(s.get("peak_bytes_in_use", 0) for s in stats),
                 "limit": min(s.get("bytes_limit", 0) for s in stats)}
+
+
+def _require_free(path: Path, need_bytes: float, level: str) -> None:
+    free = shutil.disk_usage(path).free
+    if free < need_bytes:
+        raise BenchError(f"{level} holds {free / 1e9:.2f} GB free, the cell's "
+                         f"checkpoints there need {need_bytes / 1e9:.2f} GB")
 
 
 def read_metrics(metrics: List[Dict[str, Any]], rec: Dict[str, Any],
@@ -173,6 +198,7 @@ def main(argv=None, *, t_start: Optional[float] = None) -> int:
 
     import jax
     from bench import checks as chk
+    from bench import ckpt
     from bench.job import load_module
     from bench.trace import Tracer
     from repro.launch.compile_cache import use_compile_cache
@@ -183,14 +209,19 @@ def main(argv=None, *, t_start: Optional[float] = None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     shutil.rmtree(RUNS, ignore_errors=True)
     (RUNS / "ckpt").mkdir(parents=True)
-    ctx = Context(cell=cell, seed=args.seed, seconds=args.seconds, devices=devices,
-                  tracer=Tracer(bool(args.trace), str(RUNS / "trace")),
-                  t_start=t_start, ckpt_root=RUNS / "ckpt")
+    ram = None
     try:
+        ram = ckpt.place_l1(RUNS / "ckpt", cell.config)
+        ctx = Context(cell=cell, seed=args.seed, seconds=args.seconds, devices=devices,
+                      tracer=Tracer(bool(args.trace), str(RUNS / "trace")),
+                      t_start=t_start, ckpt_root=RUNS / "ckpt",
+                      l1_ram=ram / "local" if ram else None)
         mix = load_module(cell.mix_path)
         rec = mix.run(ctx)
     finally:
         shutil.rmtree(RUNS, ignore_errors=True)
+        if ram is not None:
+            shutil.rmtree(ram, ignore_errors=True)
     rec["setup_s"] = ctx.setup_s
     rec["peaks"] = peaks
     rec["chips"] = len(devices)
@@ -214,6 +245,9 @@ def main(argv=None, *, t_start: Optional[float] = None) -> int:
             result["breakdown"] = {"device_ops": tr["device_ops"],
                                    "idle_gaps": tr["idle_gaps"]}
     result["checks"] = chk.format_checks(ctx.checks)
+    if "written" in rec:
+        print("written " + " ".join(f"{k}={v}" for k, v in rec["written"].items()),
+              file=sys.stderr)
     print("setup " + " ".join(f"{p}={t:.3f}" for p, t in ctx.marks), file=sys.stderr)
     for name, value, limit in ctx.checks:
         print(f"check {name} {value!r} limit {limit!r}", file=sys.stderr)

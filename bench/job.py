@@ -183,6 +183,17 @@ class Job:
         return self.make_state(key_from_seed(seed, 0))
 
 
+def state_shape(config: Dict[str, Any]) -> Any:
+    """The train state as shapes (the reference's parameters and their
+    AdamW moments), by ``jax.eval_shape``: nothing is allocated or
+    compiled."""
+    ref = reference(config)
+    params = jax.eval_shape(lambda: ref.init_params(key_from_seed(0, 0), config))
+    return {"params": params, "opt": {"mu": params, "nu": params,
+                                      "count": jax.ShapeDtypeStruct((), jnp.int32)},
+            "step": jax.ShapeDtypeStruct((), jnp.int32)}
+
+
 def build(config: Dict[str, Any], traffic: Dict[str, Any], seed: int,
           devices: List[Any]) -> Job:
     mcfg = model_config(config)
@@ -196,7 +207,8 @@ def build(config: Dict[str, Any], traffic: Dict[str, Any], seed: int,
     step_fn, specs, bspecs = make_train_step(model, tcfg, mesh, bstruct)
 
     init_params = lambda key: ref.init_params(key, config)
-    mine = jax.eval_shape(lambda: init_params(key_from_seed(0, 0)))
+    shape = state_shape(config)
+    mine = shape["params"]
     theirs = model.param_struct()
     if jax.tree_util.tree_structure(mine) != jax.tree_util.tree_structure(theirs) or any(
         (a.shape, a.dtype) != (b.shape, b.dtype)
@@ -210,10 +222,7 @@ def build(config: Dict[str, Any], traffic: Dict[str, Any], seed: int,
                                               sharding=NamedSharding(mesh, p)),
             tree, spec_tree)
 
-    state_shape = {"params": mine, "opt": {"mu": mine, "nu": mine,
-                                           "count": jax.ShapeDtypeStruct((), jnp.int32)},
-                   "step": jax.ShapeDtypeStruct((), jnp.int32)}
-    step = step_fn.lower(sharded(state_shape, specs), sharded(bstruct, bspecs)).compile()
+    step = step_fn.lower(sharded(shape, specs), sharded(bstruct, bspecs)).compile()
 
     def make(key):
         p = init_params(key)

@@ -13,7 +13,6 @@ uninterrupted job's, bit for bit.
 """
 from __future__ import annotations
 
-import shutil
 import time
 
 import jax
@@ -23,6 +22,10 @@ from bench import checks, ckpt
 from bench.job import build, device_digest, first_steps, reference_readings
 from bench.trace import span
 from repro.launch.train import place_state
+
+
+def saves_per_run(traffic) -> int:
+    return 1
 
 
 def run(ctx):
@@ -37,11 +40,12 @@ def run(ctx):
     digest = jax.jit(device_digest)
     saved = np.asarray(digest(state))
     shape = jax.eval_shape(lambda: state)
-    ctx.require_disk(2.2 * ckpt.tree_bytes(shape))
+    ctx.require_space(ckpt.tree_bytes(shape), saves_per_run(tr))
     mgr = ckpt.manager(ctx.ckpt_root, config)
-    mgr.save(k, {"train": state, "data": {"batch_idx": np.asarray(k, np.int32)}})
+    stats = mgr.save(k, {"train": state, "data": {"batch_idx": np.asarray(k, np.int32)}})
     mgr.wait()
     status = mgr.step_status(k, "pfs")
+    wrote = ckpt.written(config, mgr, [stats])
     mgr.close()
     ctx.mark("setup_save_flushed")
     state, m = job.step(state, job.batch(k))
@@ -56,7 +60,7 @@ def run(ctx):
         t_start = time.perf_counter()
         while True:
             with span("bench.drop_l1"):
-                shutil.rmtree(ctx.ckpt_root / "local", ignore_errors=True)
+                ckpt.drop_l1(ctx.ckpt_root)
                 ckpt.evict_from_page_cache(ctx.ckpt_root / "pfs")
             with span("bench.restore"):
                 t0 = time.perf_counter()
@@ -106,6 +110,7 @@ def run(ctx):
         "restores": [{key: c[key] for key in
                       ("resume_s", "restore_s", "place_s", "step_s", "read_s",
                        "read_bytes")} for c in cycles],
+        "written": wrote,
         "peak_bytes": mem["peak"],
         "bytes_limit": mem["limit"],
         "attempted": len(cycles),

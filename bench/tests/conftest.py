@@ -33,35 +33,61 @@ TINY_TRAFFIC = {"seq_len": 32, "global_batch": 4, "warm_steps": 3,
 TINY_LIMITS = {"loss_gap": 1e-4, "grad_gap": 1e-3, "change_gap": 1e-3}
 
 
+# cell -> (traffic kind, traffic keys over TINY_TRAFFIC, checkpoint keys
+# over TINY's): one and two saves a window, L1 on disk and in RAM
+TINY_CELLS = {
+    "tiny.save": ("save", {"save_every_steps": 3, "max_saves": 2}, {}),
+    "tiny.resume": ("resume", {"max_cycles": 2}, {}),
+    "tiny.save_ram": ("save", {"save_every_steps": 3, "max_saves": 2}, {"l1": "ram"}),
+    "tiny.save_one": ("save", {"save_every_steps": 3, "max_saves": 1}, {}),
+    "tiny.save_one_ram": ("save", {"save_every_steps": 3, "max_saves": 1}, {"l1": "ram"}),
+    "tiny.resume_ram": ("resume", {"max_cycles": 2}, {"l1": "ram"}),
+}
+
+
+def add_cell(dest: Path, name: str, kind: str, traffic: dict,
+             checkpoint: dict) -> None:
+    """Cell ``name`` in the checkout at ``dest``: the tiny configuration,
+    with ``checkpoint`` laid over its checkpoint block (a configuration
+    of its own where that is not empty), under a traffic of ``kind``
+    with ``traffic`` laid over TINY_TRAFFIC (``bench/traffic/<name with
+    _ for .>.json``), listed in every metric that lists a cell of that
+    kind."""
+    bench = json.loads((dest / "BENCHMARK.json").read_text())
+    config = f"cfg_{name.replace('.', '_')}" if checkpoint else "tiny"
+    if config not in {c["name"] for c in bench["configs"]}:
+        cfg = dict(TINY, name=config, checkpoint={**TINY["checkpoint"], **checkpoint})
+        (dest / f"bench/configs/{config}.json").write_text(json.dumps(cfg))
+        bench["configs"].append({"name": config, "source": TINY["source"],
+                                 "file": f"bench/configs/{config}.json", "reduced": [],
+                                 "why": "test size"})
+    traffic_name = name.replace(".", "_")
+    (dest / f"bench/traffic/{traffic_name}.json").write_text(
+        json.dumps({"kind": kind, **TINY_TRAFFIC, **traffic}))
+    (dest / f"bench/limits/{name}.json").write_text(json.dumps(TINY_LIMITS))
+    bench["workloads"].append({"name": name, "config": config,
+                               "traffic": traffic_name, "chips": 1, "why": "test size"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if any(w.endswith(f".{kind}") for w in m.get("workloads", [])):
+            m["workloads"].append(name)
+    (dest / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
 def make_checkout(dest: Path) -> Path:
-    """BENCHMARK.json and bench/ copied to ``dest``, plus a tiny config and
-    its two cells (``tiny.save``, ``tiny.resume``)."""
+    """BENCHMARK.json and bench/ copied to ``dest``, plus the tiny
+    configuration and its cells, ``TINY_CELLS``."""
     shutil.copy(ROOT / "BENCHMARK.json", dest / "BENCHMARK.json")
     shutil.copytree(ROOT / "bench", dest / "bench",
                     ignore=shutil.ignore_patterns(".runs", "__pycache__"))
-    bench = json.loads((dest / "BENCHMARK.json").read_text())
-    (dest / "bench/configs/tiny.json").write_text(json.dumps(TINY))
-    bench["configs"].append({"name": "tiny", "source": TINY["source"],
-                             "file": "bench/configs/tiny.json", "reduced": [],
-                             "why": "test size"})
-    for kind, extra in (("save", {"save_every_steps": 3, "max_saves": 2}),
-                        ("resume", {"max_cycles": 2})):
-        (dest / f"bench/traffic/tiny_{kind}.json").write_text(
-            json.dumps({"kind": kind, **TINY_TRAFFIC, **extra}))
-        (dest / f"bench/limits/tiny.{kind}.json").write_text(json.dumps(TINY_LIMITS))
-        bench["workloads"].append({"name": f"tiny.{kind}", "config": "tiny",
-                                   "traffic": f"tiny_{kind}", "chips": 1,
-                                   "why": "test size"})
-        for m in bench["end_to_end"] + bench["per_layer"]:
-            if any(w.endswith(f".{kind}") for w in m.get("workloads", [])):
-                m["workloads"].append(f"tiny.{kind}")
-    (dest / "BENCHMARK.json").write_text(json.dumps(bench))
+    for name, (kind, traffic, checkpoint) in TINY_CELLS.items():
+        add_cell(dest, name, kind, traffic, checkpoint)
     return dest
 
 
 @pytest.fixture
 def checkout(tmp_path, monkeypatch):
-    """A temporary checkout whose harness runs on the CPU."""
+    """A temporary checkout whose harness runs on the CPU, with the RAM
+    root of an L1 in RAM at ``<checkout>/ram``."""
     import jax
 
     import repro.launch.compile_cache as cc
@@ -72,8 +98,11 @@ def checkout(tmp_path, monkeypatch):
     for name in [m for m in sys.modules if m.startswith("bench.")]:
         sys.modules.pop(name)
     monkeypatch.syspath_prepend(str(dest))
-    from bench import harness
+    from bench import ckpt, harness
 
+    # an L1 in RAM goes under the test's own directory
+    (tmp_path / "ram").mkdir()
+    monkeypatch.setattr(ckpt, "RAM_ROOT", tmp_path / "ram")
     # the look for a chip skipped: CPU devices, measured against the v5e's peaks
     v5e = harness.load_json(dest / "bench/peaks.json")["devices"]["TPU v5 lite"]
     monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices("cpu")[:n])

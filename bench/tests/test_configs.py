@@ -1,6 +1,7 @@
-"""The configuration loader: published keys, ``program`` fields and the
-``mesh`` layout of a configuration's file, and the FLOP count it is
-measured by."""
+"""The configuration loader: published keys, ``program`` fields, the
+``mesh`` layout and the ``checkpoint`` block of a configuration's file,
+the FLOP count it is measured by, and the bytes a run of each cell
+writes to the disk."""
 from __future__ import annotations
 
 import json
@@ -12,14 +13,18 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops
-from bench.job import HF_KEYS, load_module, make_mesh, model_config
+from bench import ckpt, flops
+from bench.job import HF_KEYS, load_module, make_mesh, model_config, state_shape
 from repro.configs import get_config
 from repro.models.config import MoEConfig
 
 ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 QWEN = json.loads((ROOT / "bench/configs/qwen1.5-0.5b.json").read_text())
 MOE = {"registry": "qwen2-moe-a2.7b"}
+# what a run may write to the disk: six runs stay under the 30 GiB at
+# which a check moves to a fresh machine
+RUN_WRITES_AT_MOST = 5 * 2**30
 
 
 def test_published_keys_alone_build_what_they_built():
@@ -110,3 +115,23 @@ def test_flops_from_the_reference_or_dense():
     assert flops.flops_per_token(QWEN, decoder, 2048) == dense == 525_631_488
     own = types.SimpleNamespace(train_flops_per_token=lambda cfg, s: 7 * s + cfg["x"])
     assert flops.flops_per_token({"x": 1}, own, 2048) == 14_337.0
+
+
+def test_checkpoint_block_of_the_published_config():
+    assert ckpt.settings(QWEN) == dict(QWEN["checkpoint"], l1="disk")
+    assert ckpt.levels_on_disk(QWEN) == ["pfs", "l1"]
+    assert ckpt.levels_on_disk(dict(QWEN, checkpoint=dict(QWEN["checkpoint"], l1="ram"))) \
+        == ["pfs"]
+
+
+@pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
+def test_a_run_writes_at_most_5_gib_to_the_disk(w):
+    """The state's bytes, from its shapes alone, times the saves a run of
+    the traffic makes, times the levels on the disk."""
+    c = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+    config = json.loads((ROOT / c["file"]).read_text())
+    traffic = json.loads((ROOT / f"bench/traffic/{w['traffic']}.json").read_text())
+    mix = load_module(ROOT / f"bench/mixes/{traffic['kind']}.py")
+    state = ckpt.tree_bytes(state_shape(config))
+    disk = state * mix.saves_per_run(traffic) * len(ckpt.levels_on_disk(config))
+    assert disk <= RUN_WRITES_AT_MOST, f"{disk / 2**30:.2f} GiB a run"

@@ -5,7 +5,8 @@ chip skipped) with one fault planted in the program: a step that returns
 its state unchanged, half of the batch left out with the mean taken over
 the rest, a value altered where the checkpoint path produces it, and the
 live state altered in the step after a save.  A sound run of the same
-cell is correct."""
+cell is correct, with one save a window or two, and with L1 on disk or
+in RAM."""
 from __future__ import annotations
 
 import jax
@@ -13,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import run_cell
+from conftest import TINY_CELLS, run_cell
 
 
 def _wrap_build(monkeypatch, wrap):
@@ -47,11 +48,14 @@ def half_batch(job, step):
     return lambda state, batch: fn(state, {"tokens": batch["tokens"][:half]})
 
 
-@pytest.mark.parametrize("cell", ["tiny.save", "tiny.resume"])
+@pytest.mark.parametrize("cell", list(TINY_CELLS))
 def test_sound_run_is_correct(checkout, capsys, cell):
+    from bench import ckpt
+
     res, err = run_cell(checkout, cell, capsys)
     assert res["correct"], err
     assert all(c["value"] <= c["limit"] for c in res["checks"].values())
+    assert list(ckpt.RAM_ROOT.iterdir()) == []
 
 
 @pytest.mark.parametrize("fault,number", [(unchanged, "change_gap"),
@@ -83,9 +87,11 @@ def test_altered_save_is_not_correct(checkout, capsys, monkeypatch):
     assert res["checks"]["pfs_leaves_differ"]["value"] > 0
 
 
-def test_state_altered_after_a_save_is_not_correct(checkout, capsys, monkeypatch):
+@pytest.mark.parametrize("cell", ["tiny.save", "tiny.save_one"])
+def test_state_altered_after_a_save_is_not_correct(checkout, capsys, monkeypatch, cell):
     """Each level reads back what was saved; only the replay of the
-    window's steps from the first save sees the altered state."""
+    window's steps from the first save to the second, or to the digest
+    taken where the second would fall, sees the altered state."""
     from repro.core.engine import CheckpointManager
 
     real, pending = CheckpointManager.save, []
@@ -106,7 +112,7 @@ def test_state_altered_after_a_save_is_not_correct(checkout, capsys, monkeypatch
 
     monkeypatch.setattr(CheckpointManager, "save", save)
     _wrap_build(monkeypatch, after_save)
-    res, _ = run_cell(checkout, "tiny.save", capsys)
+    res, _ = run_cell(checkout, cell, capsys)
     assert not res["correct"]
     assert res["checks"]["replay_leaves_differ"]["value"] > 0
     assert res["checks"]["l1_leaves_differ"]["value"] == 0
